@@ -53,11 +53,12 @@ def main():
 
     base = jax.jit(lambda: fsa_selected.fsa_selected(
         q_rows, k_t, v_t, sel_rows, kv_ids, kv_cnt, g=g,
-        block_q=cfg.q_block_size, block_k=b_k))
+        block_q=cfg.q_block_size, block_k=b_k, interpret=cfg.interpret))
     # ablation 1: early return off (every block walks the full cap, masked)
     no_early = jax.jit(lambda: fsa_selected.fsa_selected(
         q_rows, k_t, v_t, sel_rows, kv_ids, kv_cnt, g=g,
-        block_q=cfg.q_block_size, block_k=b_k, early_return=False))
+        block_q=cfg.q_block_size, block_k=b_k, early_return=False,
+        interpret=cfg.interpret))
     # ablation 2: group folding off (per-head calls, M = B_Q)
     def per_head():
         outs = []
@@ -66,7 +67,8 @@ def main():
             sh = sel_rows.reshape(h_k, n, g, -1)[:, :, gi]
             outs.append(fsa_selected.fsa_selected(
                 qh, k_t, v_t, sh, kv_ids, kv_cnt, g=1,
-                block_q=cfg.q_block_size, block_k=b_k))
+                block_q=cfg.q_block_size, block_k=b_k,
+                interpret=cfg.interpret))
         return jnp.stack(outs)
     no_fold = jax.jit(per_head)
 

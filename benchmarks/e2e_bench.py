@@ -12,7 +12,7 @@ import time
 import jax
 
 from repro.configs import get_config, reduced
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import build
 from repro.models.registry import make_reduced_batch
@@ -36,7 +36,7 @@ def bench_arch(arch: str, seq: int = 256, batch: int = 2):
         cfg = dataclasses.replace(reduced(get_config(arch)),
                                   attention=attn, n_layers=4)
         model = build(cfg)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             params = model.init(jax.random.PRNGKey(0))
             batch_data = make_reduced_batch(cfg, jax.random.PRNGKey(1),
                                             batch, seq)

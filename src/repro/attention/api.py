@@ -69,7 +69,7 @@ def nsa_attention(params, gates, q, k, v, cache=None, *, cfg,
     elif mode == "decode":
         seq_len, g = k.shape[0], q.shape[0] // k.shape[1]
     elif mode == "paged_decode":
-        seq_len, g = 0, q.shape[1] // k.shape[2]
+        seq_len, g = 0, q.shape[1] // k.shape[1]
     else:
         raise ValueError(f"unknown attention mode: {mode}")
 

@@ -302,7 +302,7 @@ def _paged_sel_win_ref(q, k_pages, v_pages, page_table, idx, valid, pos,
     Returns (out_sel, out_win): each (h, dv) float32.
     """
     h, d = q.shape
-    p_sz, h_k = k_pages.shape[1], k_pages.shape[2]
+    h_k, p_sz = k_pages.shape[1], k_pages.shape[2]
     g = h // h_k
 
     # --- selected branch: gather exactly the T physical pages per KV head
@@ -310,8 +310,8 @@ def _paged_sel_win_ref(q, k_pages, v_pages, page_table, idx, valid, pos,
     t = idx.shape[-1]
     phys = page_table[idx]                                  # (h_k, T)
     hk_i = jnp.arange(h_k)
-    k_sel = jax.vmap(lambda ph, i: k_pages[ph, :, i])(phys, hk_i)
-    v_sel = jax.vmap(lambda ph, i: v_pages[ph, :, i])(phys, hk_i)
+    k_sel = jax.vmap(lambda ph, i: k_pages[ph, i])(phys, hk_i)
+    v_sel = jax.vmap(lambda ph, i: v_pages[ph, i])(phys, hk_i)
     k_sel = k_sel.reshape(h_k, t * p_sz, d)                 # (h_k, T·P, d)
     v_sel = v_sel.reshape(h_k, t * p_sz, -1)
     tok_pos = (idx[..., None] * p_sz + jnp.arange(p_sz)).reshape(h_k, t * p_sz)
@@ -346,7 +346,7 @@ def paged_decode_attention(gates, q, k_pages, v_pages, page_tables,
       selected    the T pages named by ``page_table[idx]`` per slot
       sliding     the trailing ceil(W/B_K)+1 pages per slot
 
-    gates: (B, h, 3); q: (B, h, d); k_pages/v_pages: (N_pages, P, h_k, d*);
+    gates: (B, h, 3); q: (B, h, d); k_pages/v_pages: (N_pages, h_k, P, d*);
     page_tables: (B, max_pages) int32; cmp_k/cmp_v: (B, N_cmp_max, h_k, d*);
     pos: (B,).  Returns (B, h, dv).
 
@@ -359,7 +359,7 @@ def paged_decode_attention(gates, q, k_pages, v_pages, page_tables,
     ``sparse.decode_cmp_and_select`` on both paths.
     """
     b, h, d = q.shape
-    p_sz, h_k = k_pages.shape[1], k_pages.shape[2]
+    h_k, p_sz = k_pages.shape[1], k_pages.shape[2]
     assert p_sz == cfg.block_size, "page size must equal the NSA block size"
     g = h // h_k
     s_max = page_tables.shape[1] * p_sz
@@ -558,7 +558,7 @@ def _reference_backend(params, gates, q, k, v, cache, cfg, mode,
         # dense-cache decode path on them — checks the paged organizations
         # at a different gather granularity (whole view vs selected pages)
         tables, pos = cache["page_tables"], cache["pos"]
-        s_max = tables.shape[1] * k.shape[1]
+        s_max = tables.shape[1] * k.shape[2]
         rows = jnp.arange(s_max)
         k_view = jax.vmap(gather_rows, in_axes=(None, 0, None))(k, tables, rows)
         v_view = jax.vmap(gather_rows, in_axes=(None, 0, None))(v, tables, rows)

@@ -73,7 +73,7 @@ class AttentionRequest:
     g: int = 1
     needs_grad: bool = False
     paged: bool = False
-    interpret: bool = True
+    interpret: bool = False
     platform: str = "cpu"
 
 

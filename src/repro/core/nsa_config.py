@@ -18,6 +18,18 @@ Notation follows the paper (Table 1):
 from __future__ import annotations
 
 import dataclasses
+import functools
+
+import jax
+
+
+@functools.cache
+def platform_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode, decided once per
+    process from the platform: compiled on TPU, interpreted elsewhere (the
+    CPU test runs).  Read lazily, so importing a config never initialises a
+    JAX backend."""
+    return jax.default_backend() != "tpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,7 +48,8 @@ class KernelPolicy:
 
     # --- kernel tuning knobs ---
     q_block_size: int = 128        # B_Q: query tokens per FSA batch (MXU M dim)
-    interpret: bool = True         # Pallas interpret mode (no TPU in container)
+    # Pallas interpret mode; None = ``platform_interpret()`` (compiled on TPU)
+    interpret: bool | None = None
     # slots folded per M block in the paged-decode kernel (0 = auto: fill the
     # MXU M dim to >= 8 rows)
     paged_slot_block: int = 0
@@ -106,6 +119,8 @@ class NSAConfig:
 
     @property
     def interpret(self) -> bool:
+        if self.policy.interpret is None:
+            return platform_interpret()
         return self.policy.interpret
 
     @property

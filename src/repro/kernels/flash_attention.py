@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -113,7 +112,7 @@ def _kv_band(block_q, block_k, offset, causal, window):
 def flash_attention(q, k, v, *, g: int, causal: bool = True,
                     window: int | None = None, block_q: int = 128,
                     block_k: int = 128, valid_k: int | None = None,
-                    offset: int | None = None, interpret: bool = True,
+                    offset: int | None = None, interpret: bool = False,
                     return_lse: bool = False):
     """q: (h_K, Nq·g, d); k, v: (h_K, Nk, d). Returns (h_K, Nq·g, d).
 
@@ -166,7 +165,7 @@ def flash_attention(q, k, v, *, g: int, causal: bool = True,
                 pltpu.VMEM((rows, 128), jnp.float32),
                 pltpu.VMEM((rows, dv), jnp.float32),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q, k, v)
@@ -236,7 +235,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def flash_attention_dq(q, k, v, do, lse, delta, *, g: int, causal: bool = True,
                        window: int | None = None, block_q: int = 128,
                        block_k: int = 128, valid_k: int | None = None,
-                       offset: int | None = None, interpret: bool = True):
+                       offset: int | None = None, interpret: bool = False):
     """dQ in the forward loop order (grid (h_K, q-blocks, kv-blocks)).
     Returns (h_K, Nq·g, d) float32."""
     h_k, rows_total, d = q.shape
@@ -272,7 +271,7 @@ def flash_attention_dq(q, k, v, do, lse, delta, *, g: int, causal: bool = True,
             out_specs=pl.BlockSpec((1, rows, d), q_index),
             out_shape=jax.ShapeDtypeStruct((h_k, rows_total, d), jnp.float32),
             scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q, k, v, do, lse, delta)
@@ -329,7 +328,7 @@ def flash_attention_dkv(q, k, v, do, lse, delta, *, g: int,
                         causal: bool = True, window: int | None = None,
                         block_q: int = 128, block_k: int = 128,
                         valid_k: int | None = None, offset: int | None = None,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """dK/dV with kv blocks in the outer (parallel) grid dim — each kv block
     owns its gradient tile, q blocks walk sequentially (mirroring the
     forward's clamp: out-of-band q steps re-touch a resident block).
@@ -388,7 +387,7 @@ def flash_attention_dkv(q, k, v, do, lse, delta, *, g: int,
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, dv_dim), jnp.float32),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q, k, v, do, lse, delta)

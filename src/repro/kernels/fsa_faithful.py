@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -115,7 +114,7 @@ def _reduce_kernel(kv_cnt, obuf_ref, o_ref, acc_scr):
 
 def fsa_faithful(q_rows, k, v, sel_rows, kv_ids, kv_cnt, q_ids, slot_ids, q_cnt,
                  *, g: int, block_q: int, block_k: int,
-                 seq_len: int | None = None, interpret: bool = True,
+                 seq_len: int | None = None, interpret: bool = False,
                  return_lse: bool = False):
     """Three-kernel FSA (paper structure). Same I/O contract as fsa_selected.
 
@@ -155,7 +154,7 @@ def fsa_faithful(q_rows, k, v, sel_rows, kv_ids, kv_cnt, q_ids, slot_ids, q_cnt,
             ),
             out_shape=jax.ShapeDtypeStruct((h_k, rows_total, 128),
                                            jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(kv_ids, kv_cnt, q_rows, k, sel_rows)
@@ -194,7 +193,7 @@ def fsa_faithful(q_rows, k, v, sel_rows, kv_ids, kv_cnt, q_ids, slot_ids, q_cnt,
             ),
             out_shape=jax.ShapeDtypeStruct((h_k, nq, cap + 1, rows, dv),
                                            jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(q_ids, slot_ids, q_cnt, q_rows, k, v, sel_rows, lse)
@@ -216,7 +215,7 @@ def fsa_faithful(q_rows, k, v, sel_rows, kv_ids, kv_cnt, q_ids, slot_ids, q_cnt,
             ),
             out_shape=jax.ShapeDtypeStruct((h_k, rows_total, dv),
                                            q_rows.dtype),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(kv_cnt, obuf)

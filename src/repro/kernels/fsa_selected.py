@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -101,7 +100,7 @@ def _kernel(kv_ids, kv_cnt, q_ref, k_ref, v_ref, sel_ref, o_ref, *rest,
 
 def fsa_selected(q_rows, k, v, sel_rows, kv_ids, kv_cnt, *, g: int,
                  block_q: int, block_k: int, seq_len: int | None = None,
-                 interpret: bool = True, early_return: bool = True,
+                 interpret: bool = False, early_return: bool = True,
                  return_lse: bool = False):
     """Returns (h_K, N·g, d) selected-attention output (zeros for maskless rows).
 
@@ -154,7 +153,7 @@ def fsa_selected(q_rows, k, v, sel_rows, kv_ids, kv_cnt, *, g: int,
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shape if return_lse else out_shape[0],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(kv_ids, kv_cnt, q_rows, k, v, sel_rows)

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -83,7 +82,7 @@ def _dq_kernel(kv_ids, kv_cnt, q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref,
 
 def fsa_selected_dq(q_rows, k, v, sel_rows, do_rows, lse, delta, kv_ids,
                     kv_cnt, *, g: int, block_q: int, block_k: int,
-                    seq_len: int | None = None, interpret: bool = True):
+                    seq_len: int | None = None, interpret: bool = False):
     """dQ in the FSA forward loop order.  Returns (h_K, N·g, d) float32."""
     h_k, rows_total, d = q_rows.shape
     dv = v.shape[-1]
@@ -118,7 +117,7 @@ def fsa_selected_dq(q_rows, k, v, sel_rows, do_rows, lse, delta, kv_ids,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((h_k, rows_total, d), jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(kv_ids, kv_cnt, q_rows, k, v, sel_rows, do_rows, lse, delta)
@@ -139,7 +138,7 @@ def _dkv_kernel(q_ids, q_cnt, q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref,
 
     @pl.when(j < q_cnt[hk, ib])
     def _step():
-        qb = q_ids[hk, ib, j]
+        qb = q_ids[(hk * pl.num_programs(1) + ib) * capq + j]
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -171,8 +170,11 @@ def _dkv_kernel(q_ids, q_cnt, q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref,
 
 def fsa_selected_dkv(q_rows, k, v, sel_rows, do_rows, lse, delta, q_ids,
                      q_cnt, *, g: int, block_q: int, block_k: int,
-                     seq_len: int | None = None, interpret: bool = True):
+                     seq_len: int | None = None, interpret: bool = False):
     """dK/dV in the selected-block order (occurrence lists).
+
+    ``q_ids`` is prefetched flattened: scalar memory pads a table's minor
+    dim to 128 words, which a 3-D (h_K, nb, nq) table at nq=32 would pay 4x.
 
     Returns (dk, dv): (h_K, nb·B_K, d) / (h_K, nb·B_K, dv) float32 — padded
     to whole KV blocks; slice to seq_len and cast at the call site."""
@@ -188,7 +190,7 @@ def fsa_selected_dkv(q_rows, k, v, sel_rows, do_rows, lse, delta, q_ids,
                                block_k=block_k, seq_len=seq_len)
 
     def _q_index(hk, ib, j, ids, cnt):
-        return (hk, ids[hk, ib, j], 0)
+        return (hk, ids[(hk * nb + ib) * capq + j], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -222,7 +224,8 @@ def fsa_selected_dkv(q_rows, k, v, sel_rows, do_rows, lse, delta, q_ids,
                 jax.ShapeDtypeStruct((h_k, nb * block_k, dv_dim),
                                      jnp.float32),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
-        )(q_ids, q_cnt, q_rows, k, v, sel_rows, do_rows, lse, delta)
+        )(q_ids.reshape(-1), q_cnt, q_rows, k, v, sel_rows, do_rows, lse,
+          delta)

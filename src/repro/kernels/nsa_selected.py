@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -68,7 +67,7 @@ def _kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def nsa_selected(q_pad, k, v, idx, *, block_k: int,
-                 seq_len: int | None = None, interpret: bool = True):
+                 seq_len: int | None = None, interpret: bool = False):
     """q_pad: (h_K, N, g_pad, d); idx: (h_K, N, T). Returns like q_pad.
 
     ``seq_len`` is the logical key count when k/v carry padding rows up to a
@@ -103,7 +102,7 @@ def nsa_selected(q_pad, k, v, idx, *, block_k: int,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((h_k, n, g_pad, dv), q_pad.dtype),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(idx, q_pad, k, v)

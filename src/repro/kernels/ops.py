@@ -53,7 +53,7 @@ def paged_decode_attention_batched(gates, q, k_pages, v_pages, page_tables,
     """Batched multi-slot NSA paged decode (compat wrapper; see
     ``repro.attention.backends.paged_decode_attention`` for the semantics).
 
-    gates: (B, h, 3); q: (B, h, d); k_pages/v_pages: (N_pages, P, h_k, d*);
+    gates: (B, h, 3); q: (B, h, d); k_pages/v_pages: (N_pages, h_k, P, d*);
     page_tables: (B, max_pages) int32; cmp_k/cmp_v: (B, N_cmp_max, h_k, d*);
     pos: (B,).  Returns (B, h, dv).
     """

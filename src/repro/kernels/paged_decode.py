@@ -36,7 +36,8 @@ idle padding slots) are masked, which leaves their softmax state untouched.
 
 Inputs (layouts produced by ``ops.paged_decode_attention_batched``):
   q_rows:      (h_K, B·g, d)     slot-major, group-head-minor rows
-  k/v_pages:   (N_pages, P, h_K, d*)  the shared paged pools
+  k/v_pages:   (N_pages, h_K, P, d*)  the shared paged pools; one grid step
+               fetches one (P, d*) tile: one page of one KV head
   pages:       (h_K, nsb, S)     scalar-prefetch: physical page per step
   blks:        (h_K, nsb, S)     scalar-prefetch: logical block id (-1 pad)
   pos:         (B,)              scalar-prefetch: per-slot absolute position
@@ -50,7 +51,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -80,16 +80,18 @@ def _kernel(pages, blks, pos, q_ref, k_ref, v_ref, o_sel_ref, o_win_ref,
     p = pos[sb * block_s + slot]
 
     q = q_ref[0].astype(jnp.float32)                          # (rows, d)
-    k = k_ref[:, :, 0, :].reshape(page_size, -1).astype(jnp.float32)
-    v = v_ref[:, :, 0, :].reshape(page_size, -1).astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                       # (P, dk)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
     row_slot = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) // g
     kpos = blk * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (rows, page_size), 1)
-    mask = (row_slot == slot) & (blk >= 0) & (kpos <= p)
-    mask &= jnp.where(is_sel, True, kpos > p - window)
+    # sliding steps also drop keys left of the window (a scalar bound: Mosaic
+    # cannot select between boolean vectors)
+    lo = jnp.where(is_sel, -1, p - window)
+    mask = (row_slot == slot) & (blk >= 0) & (kpos <= p) & (kpos > lo)
     s = jnp.where(mask, s, NEG_INF)
 
     def _accum(b):
@@ -159,16 +161,16 @@ def build_decode_steps(idx, valid, page_tables, pos, *, window: int,
 
 def paged_decode(q_rows, k_pages, v_pages, pages, blks, pos, *, g: int,
                  block_s: int, num_sel: int, window: int,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Selected + sliding branch attention over paged KV for B folded slots.
 
-    q_rows: (h_K, B·g, d); k/v_pages: (N_pages, P, h_K, d*); pages/blks:
+    q_rows: (h_K, B·g, d); k/v_pages: (N_pages, h_K, P, d*); pages/blks:
     (h_K, nsb, block_s·steps_per_slot) from ``build_decode_steps``; pos: (B,).
     Returns (o_sel, o_win): each (h_K, B·g, dv) float32 (zeros where a branch
     saw no unmasked key — matching ``_safe_softmax`` on fully-masked rows).
     """
     h_k, rows_total, d = q_rows.shape
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     dk = k_pages.shape[-1]
     dv = v_pages.shape[-1]
     nsb = pages.shape[1]
@@ -189,10 +191,10 @@ def paged_decode(q_rows, k_pages, v_pages, pages, blks, pos, *, g: int,
                          lambda hk, sb, j, pg, bl, ps: (hk, sb, 0)),
             # kv index_map composed through the page table: ``pg`` already
             # holds page_table[ids], so one grid step fetches one physical page
-            pl.BlockSpec((1, page_size, 1, dk),
-                         lambda hk, sb, j, pg, bl, ps: (pg[hk, sb, j], 0, hk, 0)),
-            pl.BlockSpec((1, page_size, 1, dv),
-                         lambda hk, sb, j, pg, bl, ps: (pg[hk, sb, j], 0, hk, 0)),
+            pl.BlockSpec((1, 1, page_size, dk),
+                         lambda hk, sb, j, pg, bl, ps: (pg[hk, sb, j], hk, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dv),
+                         lambda hk, sb, j, pg, bl, ps: (pg[hk, sb, j], hk, 0, 0)),
         ],
         out_specs=[out_spec, out_spec],
         scratch_shapes=[
@@ -208,7 +210,7 @@ def paged_decode(q_rows, k_pages, v_pages, pages, blks, pos, *, g: int,
             out_shape=[
                 jax.ShapeDtypeStruct((h_k, rows_total, dv), jnp.float32),
                 jax.ShapeDtypeStruct((h_k, rows_total, dv), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(pages, blks, pos, q_rows, k_pages, v_pages)

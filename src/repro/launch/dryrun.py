@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, all_configs, get_config
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import make_serve_step, make_train_step
 from repro.models import build, input_specs, supports_shape
 from repro.optim import AdamWConfig, opt_state_specs
@@ -61,7 +61,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     model = build(cfg)
     t0 = time.time()
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         batch = input_specs(cfg, shape)
         if shape.mode == "prefill":
             # serving prefill: populate decode caches from the prompt batch

@@ -5,27 +5,11 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.sharding
-from jax.sharding import Mesh
-
-# jax >= 0.5 gained explicit axis types; on older releases (container pins
-# 0.4.37) Mesh takes no ``axis_types`` argument and all axes are "auto".
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(devices, axes):
-    if _AXIS_TYPE is not None:
-        return Mesh(devices, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return Mesh(devices, axes)
-
-
-def mesh_context(mesh: Mesh):
-    """Context manager activating ``mesh`` (jax.set_mesh on new jax, the
-    Mesh context manager on 0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serving import Engine as PagedEngine
 from repro.serving import Request as ServeRequest
@@ -140,6 +141,7 @@ def main():
                          "n_kv_heads, data must divide --slots)")
     ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -23,7 +23,8 @@ from repro import telemetry
 from repro.checkpoint import ckpt
 from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import build
 from repro.optim import AdamWConfig, init_opt_state
@@ -45,7 +46,7 @@ def train_loop(cfg, *, steps: int, batch: int, seq: int, mesh,
     ns = lambda tree: jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
                                    is_leaf=lambda x: isinstance(x, P))
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         pspecs = partition.param_specs(params, mesh)
         from repro.optim import opt_state_specs
@@ -132,6 +133,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

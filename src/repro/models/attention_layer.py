@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import attention as uattn
-from repro.core.paging import gather_rows, scatter_rows
+from repro.core.paging import gather_rows, init_pool, scatter_rows
 from repro.core import compression, gating, sparse
 from repro.models.layers import apply_rope, dense_init, rms_norm
 from repro.parallel.axes import shard
@@ -282,12 +282,12 @@ def init_paged_attn_cache(cfg, num_pages: int, num_cmp_pages: int):
         dk = dv = cfg.hd()
         hk = cfg.n_kv_heads
     cache = {
-        "k_pages": jnp.zeros((num_pages, pp, hk, dk), dtype),
-        "v_pages": jnp.zeros((num_pages, pp, hk, dv), dtype),
+        "k_pages": init_pool(num_pages, hk, pp, dk, dtype),
+        "v_pages": init_pool(num_pages, hk, pp, dv, dtype),
     }
     if cfg.attention == "nsa":
-        cache["cmp_k_pages"] = jnp.zeros((num_cmp_pages, pp, hk, dk), dtype)
-        cache["cmp_v_pages"] = jnp.zeros((num_cmp_pages, pp, hk, dv), dtype)
+        cache["cmp_k_pages"] = init_pool(num_cmp_pages, hk, pp, dk, dtype)
+        cache["cmp_v_pages"] = init_pool(num_cmp_pages, hk, pp, dv, dtype)
     return cache
 
 

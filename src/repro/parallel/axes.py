@@ -2,7 +2,8 @@
 
 Model code annotates activations with *logical* axis names via ``shard``;
 the mapping to physical mesh axes lives here, so models stay mesh-agnostic.
-Outside a mesh context (unit tests, single CPU) annotations are no-ops.
+Outside a mesh context (unit tests, single CPU) and inside a ``shard_map``
+over the whole mesh, annotations are no-ops.
 """
 from __future__ import annotations
 
@@ -32,37 +33,6 @@ DEFAULT_RULES: dict[str, object] = {
 _local = threading.local()
 
 
-def axis_size(axis: str) -> int:
-    """Size of a named mesh axis inside shard_map (jax.lax.axis_size is
-    missing on 0.4.x; psum of 1 is the portable spelling)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
-
-
-def pvary(x, axes):
-    """jax.lax.pvary where it exists (newer shard_map varying-type checks);
-    identity on 0.4.x, which has no varying types."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axes)
-    return x
-
-
-def current_mesh():
-    """The ambient mesh (abstract on jax >= 0.5, physical on 0.4.x).
-
-    Both objects expose ``.empty``, ``.shape`` and ``.axis_names``, which is
-    all ``resolve``/``shard`` need.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax._src.mesh import thread_resources
-    return thread_resources.env.physical_mesh
-
-
 def current_rules() -> dict[str, object]:
     return getattr(_local, "rules", DEFAULT_RULES)
 
@@ -82,7 +52,7 @@ def resolve(*names: str | None, shape: tuple[int, ...] | None = None) -> P:
     """Map logical names to mesh axes; axes that do not divide the
     corresponding dim (e.g. 8 KV heads over a 16-way model axis) are dropped."""
     rules = current_rules()
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     sizes = dict(mesh.shape) if not mesh.empty else {}
     if shape is not None:  # tolerate rank mismatch (e.g. decode drops seq dim)
         names = tuple(names)[:len(shape)] + (None,) * max(0, len(shape) - len(names))
@@ -110,9 +80,10 @@ def resolve(*names: str | None, shape: tuple[int, ...] | None = None) -> P:
 
 def shard(x, *names: str | None):
     """Constrain activation ``x`` to the resolved logical sharding (no-op
-    outside a mesh context)."""
-    mesh = current_mesh()
-    if mesh.empty:
+    outside a mesh context, and inside ``shard_map`` over every mesh axis,
+    where each device already holds its local block)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.are_all_axes_manual:
         return x
     return jax.lax.with_sharding_constraint(
         x, resolve(*names, shape=tuple(x.shape)))

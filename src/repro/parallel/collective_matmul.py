@@ -20,20 +20,19 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.axes import axis_size, pvary
 
 
 def _ag_matmul_body(x_shard, w_local, *, axis: str):
     """x_shard: (S/n, D) local sequence shard; w_local: (D, F/n) local cols.
     Returns (S, F/n): the full-sequence activation for the local columns."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     s_shard = x_shard.shape[0]
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     out = jnp.zeros((s_shard * n, w_local.shape[1]), x_shard.dtype)
     # mark the accumulator as device-varying for the shard_map scan typing
-    out = pvary(out, (axis,))
+    out = jax.lax.pcast(out, (axis,), to="varying")
 
     def step(carry, i):
         x_cur, out = carry
@@ -51,9 +50,7 @@ def _ag_matmul_body(x_shard, w_local, *, axis: str):
 def all_gather_matmul(x, w, mesh, *, axis: str = "model"):
     """x: (S, D) sharded P(axis, None); w: (D, F) sharded P(None, axis).
     Returns (S, F) sharded P(None, axis) — same math as (all_gather(x) @ w)."""
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ag_matmul_body, axis=axis),
         mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),

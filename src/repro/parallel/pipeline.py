@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.axes import axis_size, pvary
 
 
 def pipeline_forward(stage_fn, stage_params, x, *, axis: str = "pipe"):
@@ -32,7 +31,7 @@ def pipeline_forward(stage_fn, stage_params, x, *, axis: str = "pipe"):
     Returns (n_micro, B_micro, S, D) final-stage outputs (valid on the last
     stage; callers psum-select or gather as needed).
     """
-    n_stages = axis_size(axis)
+    n_stages = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     n_micro = x.shape[0]
     ticks = n_micro + n_stages - 1
@@ -57,7 +56,8 @@ def pipeline_forward(stage_fn, stage_params, x, *, axis: str = "pipe"):
         nxt = jax.lax.ppermute(out, axis, perm)
         return (nxt, outputs), None
 
-    init = pvary((jnp.zeros_like(x[0]), jnp.zeros_like(x)), (axis,))
+    init = jax.lax.pcast((jnp.zeros_like(x[0]), jnp.zeros_like(x)), (axis,),
+                         to="varying")
     (_, outputs), _ = jax.lax.scan(tick, init, jnp.arange(ticks))
     # broadcast final outputs from the last stage to all groups
     outputs = jax.lax.ppermute(
@@ -90,9 +90,7 @@ def make_pipelined_backbone(block_fn, n_layers: int, n_stages: int,
         # stacked_params leading dim = n_layers -> (n_stages, per, ...)
         grouped = jax.tree.map(
             lambda a: a.reshape((n_stages, per) + a.shape[1:]), stacked_params)
-        from jax.experimental.shard_map import shard_map
-
-        pipe = shard_map(
+        pipe = jax.shard_map(
             functools.partial(pipeline_forward, stage_fn, axis=axis),
             mesh=mesh,
             in_specs=(P(axis), P()),

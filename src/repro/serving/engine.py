@@ -82,8 +82,7 @@ class Engine:
                     cfg.nsa.policy, paged_backend=backend)))
         self.cfg = cfg
         self.model = build(cfg)
-        self.params = (params if params is not None
-                       else self.model.init(jax.random.PRNGKey(seed)))
+        self.params = self._init_params(params, seed)
         self.cache = self._make_cache(cfg, n_slots, max_len,
                                       num_pages=num_pages)
         p = self.cache.page_size
@@ -135,9 +134,17 @@ class Engine:
             if metrics_port is not None else None)
 
     # --------------------------------------------------- construction hooks
-    # Overridden by ``serving.sharded.ShardedEngine``: sharded cache facade,
-    # per-replica prefix router, shard_mapped dispatch.  The scheduler, tick
-    # loop, and accounting above them are shared verbatim.
+    # Overridden by ``serving.sharded.ShardedEngine``: sharded params and
+    # cache facade, per-replica prefix router, shard_mapped dispatch.  The
+    # scheduler, tick loop, and accounting above them are shared verbatim.
+    def _init_params(self, params, seed):
+        """``params`` as given, else made from ``seed`` in one jitted program
+        (run eagerly, init would also hold every stacked weight's float32
+        draw on the device)."""
+        if params is not None:
+            return params
+        return jax.jit(self.model.init)(jax.random.PRNGKey(seed))
+
     def _make_cache(self, cfg, n_slots, max_len, *, num_pages):
         return PagedNSACache(cfg, n_slots, max_len, num_pages=num_pages)
 

@@ -42,33 +42,12 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import transformer
-from repro.parallel import axes
 from repro.parallel.partition import serve_param_specs
 from repro.serving.cache import PagedNSACache
 from repro.serving.engine import Engine
 from repro.serving.prefix import PrefixCache
 
-__all__ = ["MeshLayoutError", "ShardedEngine", "shard_map_compat",
-           "valid_mesh_shapes"]
-
-
-# ----------------------------------------------------------- compat helpers
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: 0.4.x takes ``check_rep``, newer
-    releases renamed it ``check_vma`` (and moved the entry point out of
-    ``jax.experimental``).  Replication checking is disabled — the psum over
-    "model" makes the logits bitwise-replicated by construction, and 0.4.x's
-    rep checker rejects the scatter/gather page ops."""
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-    except ImportError:                                  # moved in new jax
-        sm = jax.shard_map
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+__all__ = ["MeshLayoutError", "ShardedEngine", "valid_mesh_shapes"]
 
 
 # -------------------------------------------------------- mesh-shape checks
@@ -178,12 +157,14 @@ class _ShardedCache:
         # global device pytree: replica pool slabs concatenated on the page
         # dim (sharded over "data" -> each shard sees its own slab, local
         # page ids address it directly), KV heads sharded over "model"
-        self._data_spec = P(None, "data", None, "model", None)
-        data = transformer.init_lm_paged_cache(
+        self._data_spec = P(None, "data", "model", None, None)
+        init = lambda: transformer.init_lm_paged_cache(
             cfg, d * self.num_pages, d * self.num_cmp_pages)
         self._shardings = jax.tree.map(
-            lambda _: NamedSharding(mesh, self._data_spec), data)
-        self.data = jax.device_put(data, self._shardings)
+            lambda _: NamedSharding(mesh, self._data_spec),
+            jax.eval_shape(init))
+        # built in place: no device ever holds the whole pool
+        self.data = jax.jit(init, out_shardings=self._shardings)()
         self._dev_tables = None
 
     # ------------------------------------------------------------ routing
@@ -303,17 +284,25 @@ class ShardedEngine(Engine):
         self.n_data = int(mesh.shape["data"])
         self.n_model = int(mesh.shape["model"])
         super().__init__(cfg, n_slots, max_len, fused=True, **kwargs)
-        # place the (replicated-host) params per the serving layout:
-        # attention projections head-sharded over "model", rest replicated
-        specs = serve_param_specs(self.params, mesh)
-        self.params = jax.device_put(
-            self.params,
-            jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                         is_leaf=lambda x: isinstance(x, P)))
         if isinstance(self._prefix, _PrefixRouter):
             self._prefix._scheduler = self.scheduler
 
     # --------------------------------------------------- construction hooks
+    def _init_params(self, params, seed):
+        """Params in the serving layout (attention projections head-sharded
+        over "model", the rest replicated).  Params made from ``seed`` are
+        generated in place, so no device holds the whole model first."""
+        key = jax.random.PRNGKey(seed)
+        shapes = params if params is not None else jax.eval_shape(
+            self.model.init, key)
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s),
+            serve_param_specs(shapes, self.mesh),
+            is_leaf=lambda x: isinstance(x, P))
+        if params is not None:
+            return jax.device_put(params, shardings)
+        return jax.jit(self.model.init, out_shardings=shardings)(key)
+
     def _make_cache(self, cfg, n_slots, max_len, *, num_pages):
         return _ShardedCache(cfg, n_slots, max_len, self.mesh,
                              num_pages=num_pages)
@@ -335,22 +324,17 @@ class ShardedEngine(Engine):
             cfg, head_dim=cfg.hd(), n_heads=cfg.n_heads // m,
             n_kv_heads=cfg.n_kv_heads // m)
         psum_model = lambda t: jax.lax.psum(t, "model")
-        # logical-axis annotations (``axes.shard``) inside the body must be
-        # no-ops: sharding is fully explicit via shard_map specs here
-        no_rules = {k: None for k in axes.DEFAULT_RULES}
 
         def mixed_body(params, data, pf_toks, pf_t0, pf_len, dec_toks,
                        dec_pos, dec_active, tables):
-            with axes.axis_rules(no_rules):
-                return transformer.lm_paged_mixed_step(
-                    params, data, pf_toks, pf_t0, pf_len, dec_toks, dec_pos,
-                    dec_active, tables, cfg_local, reduce_fn=psum_model)
+            return transformer.lm_paged_mixed_step(
+                params, data, pf_toks, pf_t0, pf_len, dec_toks, dec_pos,
+                dec_active, tables, cfg_local, reduce_fn=psum_model)
 
         def decode_body(params, data, toks, pos, tables):
-            with axes.axis_rules(no_rules):
-                return transformer.lm_paged_decode_step(
-                    params, data, toks, pos, tables, cfg_local,
-                    reduce_fn=psum_model)
+            return transformer.lm_paged_decode_step(
+                params, data, toks, pos, tables, cfg_local,
+                reduce_fn=psum_model)
 
         pspecs = serve_param_specs(self.params, mesh)
         dspecs = jax.tree.map(lambda _: self.cache._data_spec,
@@ -358,17 +342,21 @@ class ShardedEngine(Engine):
         tspecs = {"page_table": P("data", None), "cmp_table": P("data", None),
                   "write_floor": P("data"), "cmp_write_floor": P("data")}
         row = P("data")
+        # the varying-axes check is off: it needs a ``vma`` on every Pallas
+        # kernel's out_shape, and the psum over "model" makes the logits
+        # replicated by construction
         self._mixed = jax.jit(
-            shard_map_compat(
-                mixed_body, mesh,
+            jax.shard_map(
+                mixed_body, mesh=mesh,
                 in_specs=(pspecs, dspecs, P("data", None), row, row, row,
                           row, row, tspecs),
-                out_specs=(P("data", None, None), P("data", None), dspecs)),
+                out_specs=(P("data", None, None), P("data", None), dspecs),
+                check_vma=False),
             donate_argnums=(1,))
         self._decode = jax.jit(
-            shard_map_compat(
-                decode_body, mesh,
+            jax.shard_map(
+                decode_body, mesh=mesh,
                 in_specs=(pspecs, dspecs, row, row, tspecs),
-                out_specs=(P("data", None), dspecs)),
+                out_specs=(P("data", None), dspecs), check_vma=False),
             donate_argnums=(1,))
         self._prefill = None     # sequential path unreachable (fused-only)

@@ -50,8 +50,8 @@ def _paged_state(seed=0, slots=3, g=2, max_pages=4, n_pages=24):
     return {
         "q": jax.random.normal(ks[0], (slots, h, D)),
         "gates": jax.nn.softmax(jax.random.normal(ks[1], (slots, h, 3)), -1),
-        "k_pages": jax.random.normal(ks[2], (n_pages, p_sz, H_K, D)),
-        "v_pages": jax.random.normal(ks[3], (n_pages, p_sz, H_K, D)),
+        "k_pages": jax.random.normal(ks[2], (n_pages, H_K, p_sz, D)),
+        "v_pages": jax.random.normal(ks[3], (n_pages, H_K, p_sz, D)),
         "cmp_k": jax.random.normal(ks[4], (slots, n_cmp, H_K, D)),
         "cmp_v": jax.random.normal(ks[5], (slots, n_cmp, H_K, D)),
         "tables": jnp.asarray(perm[:slots * max_pages].reshape(slots,

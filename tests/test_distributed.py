@@ -5,7 +5,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, reduced
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.parallel import partition
 from repro.parallel.axes import axis_rules, resolve
@@ -62,7 +62,7 @@ def test_train_step_under_mesh_constraint_paths():
     cfg = reduced(get_config("olmoe-1b-7b"))
     mesh = make_mesh((1, 1), ("data", "model"))
     model = build(cfg)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         state = {"params": params,
                  "opt": init_opt_state(params, AdamWConfig())}

@@ -1,10 +1,14 @@
 """Multi-device tests (8 forced host devices, run in a subprocess so the
 main pytest process keeps its single-device view)."""
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -12,14 +16,14 @@ SCRIPT = textwrap.dedent("""
     import warnings; warnings.filterwarnings("ignore")
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.launch.mesh import make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
 
     # ---- collective matmul == all_gather + matmul ----
     from repro.parallel.collective_matmul import all_gather_matmul
     mesh = make_mesh((8,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (32, 48))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda x, w: all_gather_matmul(x, w, mesh))(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w),
                                rtol=2e-4, atol=2e-4)
@@ -36,7 +40,7 @@ SCRIPT = textwrap.dedent("""
     for i in range(n_layers):
         ref = jnp.tanh(ref @ ws[i])
     fn = make_pipelined_backbone(block, n_layers, 4, mesh_p)
-    with mesh_context(mesh_p):
+    with jax.set_mesh(mesh_p):
         out = jax.jit(fn)(ws, xs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -56,7 +60,7 @@ SCRIPT = textwrap.dedent("""
     model = build(cfg)
     ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh2, s), t,
                                 is_leaf=lambda s: isinstance(s, P))
-    with mesh_context(mesh2):
+    with jax.set_mesh(mesh2):
         params = model.init(jax.random.PRNGKey(0))
         pspecs = partition.param_specs(params, mesh2)
         from repro.optim import opt_state_specs
@@ -73,12 +77,18 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
+def _child_env():
+    """A clean child env on the CPU backend (the forced host devices above
+    only exist there), with the repo's sources importable."""
+    env = {k: os.environ[k] for k in ("PATH", "HOME", "TMPDIR")
+           if k in os.environ}
+    return dict(env, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
+
+
 @pytest.mark.slow
 def test_multidevice_suite():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
-                       text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                                        "HOME": "/root"}, cwd="/root/repo",
-                       timeout=1200)
+                       text=True, env=_child_env(), cwd=REPO, timeout=1200)
     assert "collective_matmul OK" in r.stdout, r.stdout + r.stderr
     assert "pipeline OK" in r.stdout, r.stdout + r.stderr
     assert "sharded_train_step OK" in r.stdout, r.stdout + r.stderr

@@ -138,17 +138,17 @@ def test_trie_lru_eviction_order():
 
 # ------------------------------------------------------- write routing
 def test_scatter_rows_min_pos_routes_to_dump_page():
-    pool = jnp.zeros((4, 4, 2))
+    pool = jnp.zeros((4, 1, 4, 2))              # (pages, h_K, P, d)
     table = jnp.asarray([[1, 2], [3, 1]], jnp.int32)
     positions = jnp.asarray([[0, 5], [0, 5]], jnp.int32)
-    values = jnp.ones((2, 2, 2))
+    values = jnp.ones((2, 2, 1, 2))
     out = scatter_rows(pool, table, positions, values,
                        min_pos=jnp.asarray([4, 0], jnp.int32))
     # slot 0's pos 0 is below its floor -> dumped; everything else lands
-    assert float(out[1, 0].sum()) == 0          # page 1 row 0 (slot 0 pos 0)
-    assert float(out[2, 1].sum()) == 2          # slot 0 pos 5 (above floor)
-    assert float(out[3, 0].sum()) == 2          # slot 1 pos 0 (floor 0)
-    assert float(out[1, 1].sum()) == 2          # slot 1 pos 5
+    assert float(out[1, :, 0].sum()) == 0       # page 1 row 0 (slot 0 pos 0)
+    assert float(out[2, :, 1].sum()) == 2       # slot 0 pos 5 (above floor)
+    assert float(out[3, :, 0].sum()) == 2       # slot 1 pos 0 (floor 0)
+    assert float(out[1, :, 1].sum()) == 2       # slot 1 pos 5
 
 
 # ----------------------------------------------------- engine-level CoW

@@ -256,8 +256,8 @@ def _rand_paged_state(seed=0, slots=3, h_k=2, g=2, d=16, max_pages=6,
         "cfg": cfg,
         "q": jax.random.normal(ks[0], (slots, h, d)),
         "gates": jax.nn.softmax(jax.random.normal(ks[1], (slots, h, 3)), -1),
-        "k_pages": jax.random.normal(ks[2], (n_pages, p, h_k, d)),
-        "v_pages": jax.random.normal(ks[3], (n_pages, p, h_k, d)),
+        "k_pages": jax.random.normal(ks[2], (n_pages, h_k, p, d)),
+        "v_pages": jax.random.normal(ks[3], (n_pages, h_k, p, d)),
     }
     perm = np.random.default_rng(seed).permutation(np.arange(1, n_pages))
     state["tables"] = jnp.asarray(

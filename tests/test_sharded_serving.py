@@ -7,11 +7,15 @@ continuous-batching traffic — with and without the prefix cache — the
 1x1-mesh fallback to the plain engine, and the structured
 ``MeshLayoutError`` cases (model axis vs n_kv_heads, data axis vs slots).
 """
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -94,12 +98,18 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
+def _child_env():
+    """A clean child env on the CPU backend (the forced host devices above
+    only exist there), with the repo's sources importable."""
+    env = {k: os.environ[k] for k in ("PATH", "HOME", "TMPDIR")
+           if k in os.environ}
+    return dict(env, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
+
+
 @pytest.mark.slow
 def test_sharded_serving_suite():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
-                       text=True,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"}, cwd="/root/repo", timeout=1200)
+                       text=True, env=_child_env(), cwd=REPO, timeout=1200)
     assert "parity 2x4 OK" in r.stdout, r.stdout + r.stderr
     assert "parity 4x2 OK" in r.stdout, r.stdout + r.stderr
     assert "prefix parity 2x4 OK" in r.stdout, r.stdout + r.stderr

@@ -1,5 +1,6 @@
 """Optimizer / checkpoint / data / runtime / mamba / HLO-analysis tests."""
 import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -164,6 +165,27 @@ def test_mamba_decode_continues_forward():
     y_pre, (conv, ssm) = mamba_forward(p, x[:, :16], cfg)
     y_t, _, _ = mamba_decode_step(p, x[:, 16], conv, ssm, cfg)
     np.testing.assert_allclose(y_t, y_full[:, 16], atol=1e-4, rtol=1e-4)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache stays where JAX_COMPILATION_CACHE_DIR
+    puts it; with the variable unset it goes to <repo>/.jax_cache."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        # JAX reads the variable itself at start-up; mirror that here
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere/cache"
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo_cache = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert enable_compile_cache() == str(repo_cache)
+        assert jax.config.jax_compilation_cache_dir == str(repo_cache)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 # ------------------------------------------------------------ hlo analysis
