@@ -25,7 +25,6 @@ from repro.attention.backends import SELECTED_KERNELS
 from repro.attention.registry import AttentionRequest, resolve
 from repro.core.nsa_config import SELECTED_IMPL_TO_BACKEND
 from repro.telemetry import metrics as _metrics
-from repro.telemetry import trace as _trace
 
 # legacy ``ModelConfig.attn_impl`` spellings accepted as backend names;
 # derived from the registry sources so new backends stay in sync
@@ -79,13 +78,12 @@ def nsa_attention(params, gates, q, k, v, cache=None, *, cfg,
         paged=(mode == "paged_decode"), interpret=cfg.interpret,
         platform=jax.default_backend())
     fn = resolve(cfg, request, normalize_backend_name(backend, cfg))
-    # dispatch accounting: one counter bump + one span per *python-level*
-    # call (under jit that is once per trace, which is what "which backend
-    # did resolve pick, how often" means — executed-dispatch timing lives in
-    # the engine's tick spans / the profiler's named kernel scopes)
+    # dispatch accounting: one counter bump per *python-level* call (under
+    # jit that is once per trace, which is what "which backend did resolve
+    # pick, how often" means — executed time lives in the engine's tick
+    # spans and the profiler's layer and kernel scopes)
     _metrics.registry().counter("attention_dispatch_total", backend=fn.name,
                                 mode=mode, algorithm=algorithm).inc()
-    with _trace.span("attention.dispatch", backend=fn.name, mode=mode):
-        return fn(params, gates, q, k, v, cache, cfg, mode,
-                  algorithm=algorithm, causal=causal, window=window,
-                  q_chunk=q_chunk, block_s=block_s)
+    return fn(params, gates, q, k, v, cache, cfg, mode,
+              algorithm=algorithm, causal=causal, window=window,
+              q_chunk=q_chunk, block_s=block_s)

@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import indexing, sparse
+from repro.core import gating, indexing, sparse
 from repro.core import attention as core_attn
 from repro.core.nsa_config import NSAConfig
 from repro.core.paging import gather_rows
@@ -38,6 +38,7 @@ from repro.kernels import paged_decode as _paged
 from repro.kernels import ref as _ref
 from repro.attention.registry import Capabilities, register_backend
 from repro.attention.vjp import kernel_vjp
+from repro.telemetry import named_scope
 
 SELECTED_KERNELS = ("fsa", "fsa_faithful", "nsa", "reference")
 # selected-branch kernels with a fused Pallas backward (others fall back to
@@ -100,11 +101,12 @@ def _selected_run(static, q, k, v, idx, valid, want_lse):
     bq, n_pad = _q_padding(cfg, n)
 
     qp = _pad_tokens(q, n_pad)
-    idxp, validp = _normalize_selection(_pad_tokens(idx, n_pad),
-                                        _pad_tokens(valid, n_pad))
-    sel = jnp.where(validp, idxp, -1).astype(jnp.int32)       # (N, h_K, T)
-    # rows layout for sel: repeat each token's list over the g group heads
-    sel_rows = jnp.repeat(sel.transpose(1, 0, 2), g, axis=1)  # (h_K, N·g, T)
+    with named_scope("nsa.index"):
+        idxp, validp = _normalize_selection(_pad_tokens(idx, n_pad),
+                                            _pad_tokens(valid, n_pad))
+        sel = jnp.where(validp, idxp, -1).astype(jnp.int32)   # (N, h_K, T)
+        # rows layout for sel: repeat each token's list over the g heads
+        sel_rows = jnp.repeat(sel.transpose(1, 0, 2), g, axis=1)
     q_rows = _ref.rows_from_heads(qp, h_k)
     k_t, v_t, s = _kv_layout(k, v, cfg.block_size)
 
@@ -118,15 +120,17 @@ def _selected_run(static, q, k, v, idx, valid, want_lse):
         o = o[:, :, :g].transpose(1, 0, 2, 3).reshape(n_pad, h, -1)
         return o[:n], None
 
-    kv_ids, kv_cnt = indexing.build_qblock_union(idxp, validp, cfg, s)
+    with named_scope("nsa.index"):
+        kv_ids, kv_cnt = indexing.build_qblock_union(idxp, validp, cfg, s)
     if kernel == "fsa":
         o_rows = _fsa.fsa_selected(q_rows, k_t, v_t, sel_rows, kv_ids, kv_cnt,
                                    g=g, block_q=bq, block_k=cfg.block_size,
                                    seq_len=s, interpret=cfg.interpret,
                                    return_lse=want_lse)
     elif kernel == "fsa_faithful":
-        q_ids, slot_ids, q_cnt = indexing.build_kvblock_qlists(
-            idxp, validp, cfg, s, union_cap=kv_ids.shape[-1])
+        with named_scope("nsa.index"):
+            q_ids, slot_ids, q_cnt = indexing.build_kvblock_qlists(
+                idxp, validp, cfg, s, union_cap=kv_ids.shape[-1])
         o_rows = _faithful.fsa_faithful(q_rows, k_t, v_t, sel_rows, kv_ids,
                                         kv_cnt, q_ids, slot_ids, q_cnt, g=g,
                                         block_q=bq, block_k=cfg.block_size,
@@ -166,15 +170,15 @@ def _selected_fused_bwd(static, res, tensors, dout):
     g = h // h_k
     bq, n_pad = _q_padding(cfg, n)
 
-    idxp, validp = jnp.maximum(sel, 0), sel >= 0
-    sel_rows = jnp.repeat(sel.transpose(1, 0, 2), g, axis=1)
+    with named_scope("nsa.index"):
+        idxp, validp = jnp.maximum(sel, 0), sel >= 0
+        sel_rows = jnp.repeat(sel.transpose(1, 0, 2), g, axis=1)
+        kv_ids, kv_cnt = indexing.build_qblock_union(idxp, validp, cfg, s)
+        q_ids, _, q_cnt = indexing.build_kvblock_qlists(idxp, validp, cfg, s)
     q_rows = _ref.rows_from_heads(_pad_tokens(q, n_pad), h_k)
     k_t, v_t, s = _kv_layout(k, v, cfg.block_size)
     do_rows = _ref.rows_from_heads(_pad_tokens(dout, n_pad), h_k)
     delta = _delta_panels(do_rows, o_rows)
-
-    kv_ids, kv_cnt = indexing.build_qblock_union(idxp, validp, cfg, s)
-    q_ids, _, q_cnt = indexing.build_kvblock_qlists(idxp, validp, cfg, s)
     kw = dict(g=g, block_q=bq, block_k=cfg.block_size, seq_len=s,
               interpret=cfg.interpret)
     dq_rows = _fsa_bwd.fsa_selected_dq(q_rows, k_t, v_t, sel_rows, do_rows,
@@ -372,44 +376,44 @@ def paged_decode_attention(gates, q, k_pages, v_pages, page_tables,
     out_cmp = out_cmp[:, 0]                                  # (B, h, dv)
     idx, valid = idx[:, 0], valid[:, 0]                      # (B, h_k, T)
 
-    if kernel:
-        bs = block_s or cfg.paged_slot_block or max(1, -(-8 // g))
-        bs = min(bs, b)
-        pad = (-b) % bs
-        if pad:
-            q_p = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-            tables_p = jnp.pad(page_tables, ((0, pad), (0, 0)))
-            idx_p = jnp.pad(idx, ((0, pad), (0, 0), (0, 0)))
-            valid_p = jnp.pad(valid, ((0, pad), (0, 0), (0, 0)))
-            pos_p = jnp.pad(pos, ((0, pad),))
+    # the selected and sliding branches run together (one kernel per tick)
+    with named_scope("nsa.select"):
+        if kernel:
+            bs = block_s or cfg.paged_slot_block or max(1, -(-8 // g))
+            bs = min(bs, b)
+            pad = (-b) % bs
+            if pad:
+                q_p = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+                tables_p = jnp.pad(page_tables, ((0, pad), (0, 0)))
+                idx_p = jnp.pad(idx, ((0, pad), (0, 0), (0, 0)))
+                valid_p = jnp.pad(valid, ((0, pad), (0, 0), (0, 0)))
+                pos_p = jnp.pad(pos, ((0, pad),))
+            else:
+                q_p, tables_p, idx_p, valid_p, pos_p = (q, page_tables, idx,
+                                                        valid, pos)
+            bp = b + pad
+            with named_scope("nsa.index"):
+                pages, blks = _paged.build_decode_steps(
+                    idx_p, valid_p, tables_p, pos_p, window=cfg.window_size,
+                    page_size=p_sz, block_s=bs)
+            q_rows = (q_p.reshape(bp, h_k, g, d).transpose(1, 0, 2, 3)
+                         .reshape(h_k, bp * g, d))
+            o_sel, o_win = _paged.paged_decode(
+                q_rows, k_pages, v_pages, pages, blks,
+                pos_p.astype(jnp.int32), g=g, block_s=bs,
+                num_sel=idx.shape[-1], window=cfg.window_size,
+                interpret=cfg.interpret)
+            dv = o_sel.shape[-1]
+            unfold = lambda o: (o.reshape(h_k, bp, g, dv)
+                                .transpose(1, 0, 2, 3).reshape(bp, h, dv)[:b])
+            out_sel, out_win = unfold(o_sel), unfold(o_win)
         else:
-            q_p, tables_p, idx_p, valid_p, pos_p = (q, page_tables, idx,
-                                                    valid, pos)
-        bp = b + pad
-        pages, blks = _paged.build_decode_steps(
-            idx_p, valid_p, tables_p, pos_p, window=cfg.window_size,
-            page_size=p_sz, block_s=bs)
-        q_rows = (q_p.reshape(bp, h_k, g, d).transpose(1, 0, 2, 3)
-                     .reshape(h_k, bp * g, d))
-        o_sel, o_win = _paged.paged_decode(
-            q_rows, k_pages, v_pages, pages, blks, pos_p.astype(jnp.int32),
-            g=g, block_s=bs, num_sel=idx.shape[-1], window=cfg.window_size,
-            interpret=cfg.interpret)
-        dv = o_sel.shape[-1]
-        unfold = lambda o: (o.reshape(h_k, bp, g, dv).transpose(1, 0, 2, 3)
-                             .reshape(bp, h, dv)[:b])
-        out_sel, out_win = unfold(o_sel), unfold(o_win)
-    else:
-        out_sel, out_win = jax.vmap(
-            lambda q1, tb, i1, v1, p1: _paged_sel_win_ref(
-                q1, k_pages, v_pages, tb, i1, v1, p1, cfg))(
-                    q, page_tables, idx, valid, pos)
+            out_sel, out_win = jax.vmap(
+                lambda q1, tb, i1, v1, p1: _paged_sel_win_ref(
+                    q1, k_pages, v_pages, tb, i1, v1, p1, cfg))(
+                        q, page_tables, idx, valid, pos)
 
-    gf = gates.astype(jnp.float32)
-    out = (gf[..., 0:1] * out_cmp.astype(jnp.float32)
-           + gf[..., 1:2] * out_sel
-           + gf[..., 2:3] * out_win)
-    return out.astype(q.dtype)
+    return gating.combine(gates, out_cmp, out_sel, out_win).astype(q.dtype)
 
 
 # =====================================================================
@@ -420,14 +424,12 @@ def _kernel_nsa(params, gates, q, k, v, cfg, kernel, q_chunk):
     sliding branch on the Pallas flash kernel (the old impl="kernel")."""
     out_cmp, idx, valid = core_attn.compressed_and_selection(
         params, q, k, v, cfg, q_chunk=q_chunk)
-    out_sel = selected_attention(q, k, v, idx, valid, cfg, kernel=kernel)
-    out_win = flash_attention(q, k, v, cfg, causal=True,
-                              window=cfg.window_size)
-    gf = gates.astype(jnp.float32)
-    out = (gf[..., 0:1] * out_cmp.astype(jnp.float32)
-           + gf[..., 1:2] * out_sel.astype(jnp.float32)
-           + gf[..., 2:3] * out_win.astype(jnp.float32))
-    return out.astype(q.dtype)
+    with named_scope("nsa.select"):
+        out_sel = selected_attention(q, k, v, idx, valid, cfg, kernel=kernel)
+    with named_scope("nsa.window"):
+        out_win = flash_attention(q, k, v, cfg, causal=True,
+                                  window=cfg.window_size)
+    return gating.combine(gates, out_cmp, out_sel, out_win).astype(q.dtype)
 
 
 def _register_selected_kernel_backend(name, caps):

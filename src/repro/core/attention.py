@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.core import compression, gating, selection
 from repro.core.nsa_config import NSAConfig
 from repro.core.reference import _gqa_out, _gqa_scores, _safe_softmax
+from repro.telemetry import named_scope
 
 
 def init_nsa_params(key: jax.Array, model_dim: int, num_heads: int, head_dim: int,
@@ -41,6 +42,7 @@ def _cmp_and_select_chunk(params, cfg, k, v, k_cmp, v_cmp, sel_map, n, chunk):
     return out_cmp, idx, valid
 
 
+@named_scope("nsa.compress")
 def compressed_and_selection(params, q, k, v, cfg: NSAConfig, *, q_chunk: int = 512):
     """Chunked compressed-branch output + block selection for all queries.
 

@@ -1,10 +1,12 @@
 """Device-side paged-KV indexing helpers.
 
-Low-level (no deps besides jnp) so every layer — kernels, model layers, the
-serving subsystem — can address token rows through a page table without
-upward imports.  A page table maps a slot's logical block index to a
-physical page id; page 0 is by convention a reserved dump page (idle slots
-and masked writes are routed there, keeping scatters unconditional).
+Low-level (no deps besides jnp and ``repro.telemetry``'s scopes) so every
+layer — kernels, model layers, the serving subsystem — can address token
+rows through a page table without upward imports.  Reads run under the
+``kv.gather`` scope, writes under ``kv.write``.  A page table maps a slot's
+logical block index to a physical page id; page 0 is by convention a
+reserved dump page (idle slots and masked writes are routed there, keeping
+scatters unconditional).
 
 Pool layout: ``(N_pages, h_K, P, d)``.  One page of one KV head is a
 contiguous ``(P, d)`` tile, which is the block the paged-decode kernel
@@ -16,6 +18,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.telemetry import named_scope
+
 
 def init_pool(num_pages: int, num_heads: int, page_size: int, dim: int,
               dtype) -> jnp.ndarray:
@@ -23,6 +27,7 @@ def init_pool(num_pages: int, num_heads: int, page_size: int, dim: int,
     return jnp.zeros((num_pages, num_heads, page_size, dim), dtype)
 
 
+@named_scope("kv.gather")
 def gather_rows(pool: jnp.ndarray, table: jnp.ndarray, positions: jnp.ndarray):
     """Gather token rows through a page table.
 
@@ -35,6 +40,7 @@ def gather_rows(pool: jnp.ndarray, table: jnp.ndarray, positions: jnp.ndarray):
     return pool[table[positions // p], :, positions % p]
 
 
+@named_scope("kv.write")
 def scatter_rows(pool: jnp.ndarray, table: jnp.ndarray, positions: jnp.ndarray,
                  values: jnp.ndarray, valid: jnp.ndarray | None = None,
                  min_pos: jnp.ndarray | None = None):

@@ -24,9 +24,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import compression, selection
+from repro.core import compression, gating, selection
 from repro.core.nsa_config import NSAConfig
 from repro.core.reference import _gqa_out, _gqa_scores, _safe_softmax
+from repro.telemetry import named_scope
 
 
 def selected_gather_attention(q, k, v, idx, valid, cfg: NSAConfig, q_pos):
@@ -98,12 +99,14 @@ def _union_setup(q, k, v, idx, valid, cfg: NSAConfig, q_pos):
     b = (s + bk - 1) // bk
     cap = min(b, c * idx.shape[-1])          # static, always-correct bound
 
-    oh = jnp.zeros((c, h_k, b), bool)
-    oh = oh.at[jnp.arange(c)[:, None, None],
-               jnp.arange(h_k)[None, :, None], idx].max(valid)
-    present = oh.any(0).astype(jnp.int32)                   # (h_k, b)
-    order = jnp.argsort(1 - present, axis=-1, stable=True).astype(jnp.int32)
-    ids = order[:, :cap]                                    # (h_k, cap)
+    with named_scope("nsa.index"):
+        oh = jnp.zeros((c, h_k, b), bool)
+        oh = oh.at[jnp.arange(c)[:, None, None],
+                   jnp.arange(h_k)[None, :, None], idx].max(valid)
+        present = oh.any(0).astype(jnp.int32)               # (h_k, b)
+        order = jnp.argsort(1 - present, axis=-1,
+                            stable=True).astype(jnp.int32)
+        ids = order[:, :cap]                                # (h_k, cap)
 
     tok = ids[:, :, None] * bk + jnp.arange(bk)             # (h_k, cap, B_K)
     tok_flat = jnp.minimum(tok.reshape(h_k, cap * bk), s - 1)
@@ -239,29 +242,26 @@ def _nsa_chunk(params, cfg, k, v, k_cmp, v_cmp, sel_map, chunk,
     n = k.shape[0]
     g = q_c.shape[1] // k.shape[1]
 
-    # --- compressed branch (+ selection scores) ---
-    vis = compression.cmp_visibility(pos_c, k_cmp.shape[0], cfg)
-    p_cmp, _ = _safe_softmax(_gqa_scores(q_c, k_cmp), vis[:, None, :])
-    out_cmp = _gqa_out(p_cmp, v_cmp)
-
-    # --- selection ---
-    scores = selection.importance_scores(p_cmp, sel_map, g)
-    idx, valid = selection.select_blocks(scores, pos_c, cfg, n)
+    with named_scope("nsa.compress"):
+        # --- compressed branch (+ selection scores) ---
+        vis = compression.cmp_visibility(pos_c, k_cmp.shape[0], cfg)
+        p_cmp, _ = _safe_softmax(_gqa_scores(q_c, k_cmp), vis[:, None, :])
+        out_cmp = _gqa_out(p_cmp, v_cmp)
+        # --- selection ---
+        scores = selection.importance_scores(p_cmp, sel_map, g)
+        idx, valid = selection.select_blocks(scores, pos_c, cfg, n)
 
     # --- selected branch (FSA block-union unless the caller overrides) ---
     if selected_fn is None:
         selected_fn = selected_union_attention
-    out_sel = selected_fn(q_c, k, v, idx, valid, cfg, pos_c)
+    with named_scope("nsa.select"):
+        out_sel = selected_fn(q_c, k, v, idx, valid, cfg, pos_c)
 
-    # --- sliding branch ---
-    out_win = sliding_window_chunk(q_c, k, v, pos_c[0] - (cfg.window_size - 1), cfg, pos_c)
+    with named_scope("nsa.window"):
+        out_win = sliding_window_chunk(
+            q_c, k, v, pos_c[0] - (cfg.window_size - 1), cfg, pos_c)
 
-    gates = gates_c.astype(jnp.float32)
-    out = (
-        gates[..., 0:1] * out_cmp.astype(jnp.float32)
-        + gates[..., 1:2] * out_sel.astype(jnp.float32)
-        + gates[..., 2:3] * out_win.astype(jnp.float32)
-    )
+    out = gating.combine(gates_c, out_cmp, out_sel, out_win)
     return out.astype(q_c.dtype), (idx, valid)
 
 
@@ -283,7 +283,8 @@ def nsa_attention_sparse(
     ``_nsa_chunk``); None means the FSA block-union production path.
     """
     n, h, d = q.shape
-    k_cmp, v_cmp = compression.compress_kv(params, k, v, cfg)
+    with named_scope("nsa.compress"):
+        k_cmp, v_cmp = compression.compress_kv(params, k, v, cfg)
     sel_map = jnp.asarray(
         compression.cmp_to_sel_map(k_cmp.shape[0], cfg.num_kv_blocks(n), cfg)
     )
@@ -311,6 +312,7 @@ def nsa_attention_sparse(
     return out
 
 
+@named_scope("nsa.compress")
 def decode_cmp_and_select(q_c, k_cmp, v_cmp, pos, cfg: NSAConfig,
                           seq_len: int):
     """Shared one-token decode prologue: compressed-branch attention + top-T
@@ -359,15 +361,11 @@ def nsa_decode_step(
     pos_c = pos[None]
 
     out_cmp, idx, valid = decode_cmp_and_select(q_c, k_cmp, v_cmp, pos, cfg, s)
-    out_sel = selected_gather_attention(q_c, k_cache, v_cache, idx, valid, cfg, pos_c)
-    out_win = sliding_window_chunk(
-        q_c, k_cache, v_cache, pos - (cfg.window_size - 1), cfg, pos_c
-    )
-
-    gf = gates.astype(jnp.float32)[None]
-    out = (
-        gf[..., 0:1] * out_cmp.astype(jnp.float32)
-        + gf[..., 1:2] * out_sel.astype(jnp.float32)
-        + gf[..., 2:3] * out_win.astype(jnp.float32)
-    )
+    with named_scope("nsa.select"):
+        out_sel = selected_gather_attention(q_c, k_cache, v_cache, idx, valid,
+                                            cfg, pos_c)
+    with named_scope("nsa.window"):
+        out_win = sliding_window_chunk(
+            q_c, k_cache, v_cache, pos - (cfg.window_size - 1), cfg, pos_c)
+    out = gating.combine(gates[None], out_cmp, out_sel, out_win)
     return out[0].astype(q.dtype)

@@ -13,6 +13,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models import build
 from repro.optim import AdamWConfig, apply_updates, cosine_with_warmup
 from repro.parallel import partition
+from repro.telemetry import named_scope
 
 
 def make_train_state_specs(cfg, params_shape, mesh, opt_cfg: AdamWConfig):
@@ -65,9 +66,10 @@ def make_train_step(cfg, mesh, opt_cfg: AdamWConfig | None = None, *,
         else:
             loss, metrics, grads = grads_and_loss(params, batch)
 
-        lr_scale = schedule(opt["step"])
-        new_params, new_opt, opt_metrics = apply_updates(
-            params, grads, opt, opt_cfg, lr_scale=lr_scale)
+        with named_scope("optimizer"):
+            lr_scale = schedule(opt["step"])
+            new_params, new_opt, opt_metrics = apply_updates(
+                params, grads, opt, opt_cfg, lr_scale=lr_scale)
         metrics = {**metrics, **opt_metrics, "loss": loss,
                    "lr_scale": lr_scale}
         return {"params": new_params, "opt": new_opt}, metrics

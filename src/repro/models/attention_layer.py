@@ -30,6 +30,7 @@ from repro.core.paging import gather_rows, init_pool, scatter_rows
 from repro.core import compression, gating, sparse
 from repro.models.layers import apply_rope, dense_init, rms_norm
 from repro.parallel.axes import shard
+from repro.telemetry import named_scope
 
 
 # ------------------------------------------------------------------ params
@@ -71,6 +72,7 @@ def init_attention(key, cfg) -> dict:
 
 
 # -------------------------------------------------------------- projections
+@named_scope("attn.qkv")
 def _qkv(p, x, cfg, pos):
     """x: (B,S,D) -> q (B,S,h,dk), k (B,S,h_k,dk), v (B,S,h_k,dv)."""
     b, s, _ = x.shape
@@ -96,6 +98,7 @@ def _qkv(p, x, cfg, pos):
     return q, k, v.reshape(b, s, hk, hd)
 
 
+@named_scope("attn.out")
 def _out_proj(p, o, cfg):
     """o: (B,S,h,dv_attn) -> (B,S,D)."""
     b, s = o.shape[:2]
@@ -185,6 +188,7 @@ def attention_prefill(p, x, cfg, cache):
     return y, cache
 
 
+@named_scope("nsa.compress")
 def _emit_cmp_token(p, cfg, win_k, win_v):
     """Compress one complete (l,)-token window into a single summary token.
 

@@ -5,6 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry import named_scope
+
 
 def dense_init(key, shape, dtype, scale: float | None = None):
     fan_in = shape[0] if len(shape) > 1 else 1
@@ -55,6 +57,7 @@ def init_mlp(key, d_model: int, d_ff: int, kind: str, dtype):
     return p
 
 
+@named_scope("mlp")
 def apply_mlp(p, x, kind: str):
     from repro.parallel.axes import shard
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.models.layers import dense_init
 from repro.parallel.axes import shard
+from repro.telemetry import named_scope
 
 
 def init_moe(key, cfg) -> dict:
@@ -36,6 +37,7 @@ def init_moe(key, cfg) -> dict:
     return p
 
 
+@named_scope("mlp")
 def apply_moe(p, x, cfg):
     """x: (B,S,D) -> ((B,S,D), aux load-balancing loss)."""
     m = cfg.moe

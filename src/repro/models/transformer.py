@@ -23,6 +23,7 @@ from repro.models import mamba2, moe
 from repro.models.layers import (apply_mlp, cross_entropy, dense_init,
                                  init_mlp, rms_norm, softcap)
 from repro.parallel.axes import shard
+from repro.telemetry import named_scope
 
 AUX_LOSS_WEIGHT = 0.01
 
@@ -150,11 +151,18 @@ def backbone(params, x, cfg):
     return x_, aux
 
 
+@named_scope("embed")
+def _embed(params, tokens):
+    """Embedding rows of ``tokens`` (any shape)."""
+    return params["embed"][tokens]
+
+
 def embed_tokens(params, tokens, cfg):
-    x = params["embed"][tokens]                 # gather (B,S,D)
+    x = _embed(params, tokens)                  # gather (B,S,D)
     return shard(x, "batch", "seq_sp", "embed")
 
 
+@named_scope("lm_head")
 def _head(params, x, cfg):
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ w
@@ -190,6 +198,7 @@ def lm_loss(params, batch, cfg, *, loss_chunk: int = 1024):
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-100)
 
+    @named_scope("lm_head")
     def chunk_loss(args):
         xc, lc = args
         logits = _head(params, xc, cfg)
@@ -314,6 +323,7 @@ def init_lm_paged_cache(cfg, num_pages: int, num_cmp_pages: int):
         cfg.n_layers)}
 
 
+@named_scope("tick.decode")
 def lm_paged_decode_step(params, cache, tokens, pos, tables, cfg, *,
                          reduce_fn=None):
     """Batched decode on paged storage.
@@ -329,7 +339,7 @@ def lm_paged_decode_step(params, cache, tokens, pos, tables, cfg, *,
     pass ``lambda t: jax.lax.psum(t, "model")`` to complete it.
     """
     rf = reduce_fn if reduce_fn is not None else (lambda t: t)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
 
     def body(x, args):
         p_l, c_l = args
@@ -350,6 +360,7 @@ def lm_paged_decode_step(params, cache, tokens, pos, tables, cfg, *,
     return _head(params, x[:, None], cfg)[:, 0], cache
 
 
+@named_scope("tick.prefill")
 def lm_paged_prefill_chunks(params, cache, tokens_c, t0, length, tables, cfg,
                             *, reduce_fn=None):
     """Prefill one chunk for a BATCH of slots into paged storage.
@@ -365,7 +376,7 @@ def lm_paged_prefill_chunks(params, cache, tokens_c, t0, length, tables, cfg,
     the partial attention out-projection).
     """
     rf = reduce_fn if reduce_fn is not None else (lambda t: t)
-    x = params["embed"][tokens_c]                          # (B, C, D)
+    x = _embed(params, tokens_c)                           # (B, C, D)
 
     def body(x, args):
         p_l, c_l = args
@@ -386,6 +397,7 @@ def lm_paged_prefill_chunks(params, cache, tokens_c, t0, length, tables, cfg,
     return _head(params, x, cfg), cache
 
 
+@named_scope("tick.prefill")
 def lm_paged_mixed_step(params, cache, pf_tokens, pf_t0, pf_len,
                         dec_tokens, dec_pos, dec_active, tables, cfg, *,
                         reduce_fn=None):
@@ -406,10 +418,16 @@ def lm_paged_mixed_step(params, cache, pf_tokens, pf_t0, pf_len,
     the partial attention out-projections of BOTH sub-steps).
 
     Returns (pf_logits (B, C, V), dec_logits (B, V), cache).
+
+    The step runs under the ``tick.prefill`` scope and its decode sub-step
+    under ``tick.decode`` inside it: the layer loop that hands each layer's
+    page pools to both sub-steps counts as the prefill's, which is what a
+    mixed tick adds over a decode-only one.
     """
     rf = reduce_fn if reduce_fn is not None else (lambda t: t)
-    x_pf = params["embed"][pf_tokens]                       # (B, C, D)
-    x_dec = params["embed"][dec_tokens]                     # (B, D)
+    x_pf = _embed(params, pf_tokens)                        # (B, C, D)
+    with named_scope("tick.decode"):
+        x_dec = _embed(params, dec_tokens)                  # (B, D)
 
     def body(carry, args):
         x_pf, x_dec = carry
@@ -426,26 +444,29 @@ def lm_paged_mixed_step(params, cache, pf_tokens, pf_t0, pf_len,
             h = apply_mlp(p_l["mlp"], h, cfg.mlp)
         x_pf = x_pf + h
         # decode sub-step (one token per active slot)
-        h = rms_norm(x_dec, p_l["ln1"], cfg.norm_eps)
-        h, c_l = attn.paged_attention_decode(p_l["attn"], h, c_l, tables,
-                                             dec_pos, cfg, active=dec_active)
-        x_dec = x_dec + rf(h)
-        h = rms_norm(x_dec, p_l["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            h2, _ = moe.apply_moe(p_l["moe"], h[:, None, :], cfg)
-            h = h2[:, 0]
-        else:
-            h = apply_mlp(p_l["mlp"], h, cfg.mlp)
-        x_dec = x_dec + h
+        with named_scope("tick.decode"):
+            h = rms_norm(x_dec, p_l["ln1"], cfg.norm_eps)
+            h, c_l = attn.paged_attention_decode(
+                p_l["attn"], h, c_l, tables, dec_pos, cfg, active=dec_active)
+            x_dec = x_dec + rf(h)
+            h = rms_norm(x_dec, p_l["ln2"], cfg.norm_eps)
+            if cfg.moe is not None:
+                h2, _ = moe.apply_moe(p_l["moe"], h[:, None, :], cfg)
+                h = h2[:, 0]
+            else:
+                h = apply_mlp(p_l["mlp"], h, cfg.mlp)
+            x_dec = x_dec + h
         return (x_pf, x_dec), c_l
 
     (x_pf, x_dec), cl = jax.lax.scan(body, (x_pf, x_dec),
                                      (params["layers"], cache["layers"]))
     cache = dict(cache, layers=cl)
     x_pf = rms_norm(x_pf, params["final_norm"], cfg.norm_eps)
-    x_dec = rms_norm(x_dec, params["final_norm"], cfg.norm_eps)
-    return (_head(params, x_pf, cfg),
-            _head(params, x_dec[:, None], cfg)[:, 0], cache)
+    pf_logits = _head(params, x_pf, cfg)
+    with named_scope("tick.decode"):
+        x_dec = rms_norm(x_dec, params["final_norm"], cfg.norm_eps)
+        dec_logits = _head(params, x_dec[:, None], cfg)[:, 0]
+    return pf_logits, dec_logits, cache
 
 
 def lm_paged_prefill_chunk(params, cache, tokens_c, t0, length, tables, cfg):

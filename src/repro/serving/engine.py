@@ -398,8 +398,10 @@ class Engine:
             # rides along in the same launch)
             with telemetry.span("engine.prefill_chunk",
                                 registry=self.telemetry,
-                                fused=bool(decoding)) as sp:
-                sp.annotate(chunk_tokens=chunk_tokens)
+                                fused=bool(decoding),
+                                args={"rows": bsz * c,
+                                      "live_rows": chunk_tokens,
+                                      "decode_rows": len(decoding)}):
                 pf_logits, dec_logits, self.cache.data = self._mixed(
                     self.params, self.cache.data, jnp.asarray(pf_toks),
                     jnp.asarray(pf_t0), jnp.asarray(pf_len),
@@ -411,7 +413,9 @@ class Engine:
                 if r.first_chunk_t is None:
                     r.first_chunk_t = t_chunk
         else:   # steady-state decode: skip the (B, C) prefill sub-step
-            with telemetry.span("engine.decode", registry=self.telemetry):
+            with telemetry.span("engine.decode", registry=self.telemetry,
+                                args={"rows": bsz,
+                                      "live_rows": len(decoding)}):
                 dec_logits, self.cache.data = self._decode(
                     self.params, self.cache.data,
                     jnp.asarray(self._last_tokens),

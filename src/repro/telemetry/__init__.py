@@ -6,8 +6,9 @@
   disabled (the disabled registry hands out a no-op singleton).
 * :mod:`repro.telemetry.trace` — context-manager :func:`span`\\ s with
   wall-clock + optional device-sync timing, emitting to the registries and
-  to ``jax.profiler`` so engine tick phases and Pallas kernel regions show
-  up labeled in XLA profiles.
+  to ``jax.profiler`` (with the span's ``args`` as the event's stats) so
+  engine tick phases show up labeled in profiles; :func:`named_scope`
+  labels the layers inside jitted programs with the names in ``SCOPES``.
 * :mod:`repro.telemetry.pull` — :func:`serve_metrics`: a stdlib-only
   ``GET /metrics`` HTTP endpoint rendering a registry's ``exposition()``
   for real Prometheus scraping (``Engine(metrics_port=...)`` /
@@ -39,7 +40,7 @@ from repro.telemetry.metrics import (
     sink,
 )
 from repro.telemetry.pull import MetricsServer, serve_metrics
-from repro.telemetry.trace import SpanHandle, named_scope, span
+from repro.telemetry.trace import SCOPES, SpanHandle, named_scope, span
 
 __all__ = [
     "DEFAULT_BUCKETS_MS",
@@ -50,6 +51,7 @@ __all__ = [
     "MetricsServer",
     "NOOP",
     "Registry",
+    "SCOPES",
     "SpanHandle",
     "counter_value",
     "disable",

@@ -154,9 +154,6 @@ def test_dispatch_counter_once_per_call():
     assert telemetry.counter_value(
         snap, "attention_dispatch_total", backend="reference", mode="prefill",
         algorithm="full") == 2
-    # and the dispatch shows up as a named span
-    assert ('backend="reference",mode="prefill",span="attention.dispatch"'
-            in snap["histograms"]["span_ms"])
 
 
 def test_resolve_fallback_counter():
